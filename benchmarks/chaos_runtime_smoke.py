@@ -96,7 +96,7 @@ def phase_tcp_kill_and_rejoin(n_iterations: int = 90) -> dict:
 
     def respawn_worker0():
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"   # the parent holds the accelerator
         src_root = os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "src")
         env["PYTHONPATH"] = (src_root + os.pathsep

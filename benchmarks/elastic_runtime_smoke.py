@@ -129,7 +129,7 @@ def phase_tcp_admission(n_iterations: int = 90) -> dict:
 
     def spawn(worker: int, epoch: int = 0):
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"   # the parent holds the accelerator
         src_root = os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "src")
         env["PYTHONPATH"] = (src_root + os.pathsep

@@ -13,16 +13,17 @@ def _recompute_terms(r: dict) -> dict:
     to existing JSONL without re-compiling."""
     if r.get("status") != "ok":
         return r
-    from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+    from repro.launch.mesh import TARGET_DEVICE_KIND, chip_peaks
+    pk = chip_peaks(TARGET_DEVICE_KIND)
     chips = r["chips"]
     flops_total = r["hlo_flops_per_dev"] * chips
     flops_corr = max(flops_total, r["analytic_flops_total"])
     coll = sum(v for k, v in r["coll_bytes"].items() if k != "count")
     r = dict(r)
-    r["compute_s"] = flops_total / (chips * PEAK_FLOPS_BF16)
-    r["compute_corrected_s"] = flops_corr / (chips * PEAK_FLOPS_BF16)
-    r["memory_s"] = r["hlo_bytes_per_dev"] / HBM_BW
-    r["collective_s"] = coll / (chips * ICI_BW)
+    r["compute_s"] = flops_total / (chips * pk["flops_bf16"])
+    r["compute_corrected_s"] = flops_corr / (chips * pk["flops_bf16"])
+    r["memory_s"] = r["hlo_bytes_per_dev"] / pk["hbm_bw"]
+    r["collective_s"] = coll / (chips * pk["ici_bw"])
     r["useful_ratio"] = r["model_flops_total"] / max(flops_corr, 1.0)
     r["hbm_gb_per_dev"] = (r["arg_bytes"] + r["temp_bytes"]
                            + r["out_bytes"]) / 1e9

@@ -351,7 +351,6 @@ def _build_scan_sharded(problem: TrilevelProblem, hyper: Hyper,
                         metrics_fn: Optional[Callable], keys,
                         donate: bool, mesh, state_specs,
                         stream_spec=None, n_shards: Optional[int] = None):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     BUILD_COUNTS["scan_sharded_streamed" if stream_spec
@@ -378,12 +377,12 @@ def _build_scan_sharded(problem: TrilevelProblem, hyper: Hyper,
     data_specs = None if stream_spec is not None else \
         shd.worker_data_specs(problem.data, axis=axis)
     key_spec = None if stream_spec is None else P()
-    fn = shard_map(
+    fn = jax.shard_map(
         scan_all, mesh=mesh,
         in_specs=(state_specs, hist_specs, data_specs, key_spec,
                   P(None, axis), P()),
         out_specs=(state_specs, hist_specs),
-        check_rep=False)
+        check_vma=False)
     donate_argnums = (0, 1) if donate else ()
     return jax.jit(fn, donate_argnums=donate_argnums)
 
@@ -609,7 +608,6 @@ def _build_sweep_sharded(problem: TrilevelProblem, hyper: Hyper,
                          sweep_names: tuple, has_data: bool, mesh,
                          state_specs, stream_spec=None,
                          n_shards: Optional[int] = None):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     BUILD_COUNTS["sweep_sharded_streamed" if stream_spec
@@ -645,12 +643,12 @@ def _build_sweep_sharded(problem: TrilevelProblem, hyper: Hyper,
         shd.worker_data_specs(problem.data, axis=axis, lead=data_lead)
     key_spec = None if stream_spec is None else P()
     sweep_specs = tuple(P() for _ in sweep_names)
-    fn = shard_map(
+    fn = jax.shard_map(
         sweep_all, mesh=mesh,
         in_specs=(state_specs, hist_specs, data_specs, key_spec,
                   P(None, None, axis), sweep_specs, P()),
         out_specs=(state_specs, hist_specs),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn, donate_argnums=(0, 1))
 
 

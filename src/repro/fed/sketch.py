@@ -70,6 +70,31 @@ def sketch(tree: Any, seed: int, r: int) -> jnp.ndarray:
     return out
 
 
+def sketch_stacked(tree: Any, seed: int, r: int) -> jnp.ndarray:
+    """Count-sketch every worker slice of an (N,)-stacked pytree: (N, r),
+    equal to stacking `sketch(tree[j])`.
+
+    One flat scatter into N*r buckets, worker j's bucket k at j*r + k,
+    so every embedding-sized operand stays worker-major.  A `jax.vmap`
+    of `sketch` batches the segment-sum instead, and XLA then lays the
+    worker axis out minor: on a TPU the (8, 128) tile pads it to 128
+    lanes, a 32x blow-up of every embedding-sized leaf at N=4.  Sharded
+    over workers, each device scatters its own rows and one (N*r,)
+    all-reduce joins them."""
+    leaves, _, seeds = _leaf_seeds(tree, seed)
+    n_workers = leaves[0].shape[0]
+    offset = (r * jnp.arange(n_workers, dtype=jnp.int32))[:, None]
+    out = jnp.zeros((n_workers * r,), jnp.float32)
+    for leaf, s in zip(leaves, seeds):
+        idx, sign = _leaf_hashes(leaf.shape[1:], s, r)
+        vals = leaf.reshape(n_workers, -1).astype(jnp.float32) \
+            * sign.reshape(1, -1)
+        ids = idx.reshape(1, -1) + offset
+        out = out + jax.ops.segment_sum(vals.reshape(-1), ids.reshape(-1),
+                                        num_segments=n_workers * r)
+    return out.reshape(n_workers, r)
+
+
 def unsketch(template: Any, s_vec: jnp.ndarray, seed: int) -> Any:
     """Adjoint of `sketch`: lift an (r,) vector back to the tree space.
 
@@ -82,6 +107,23 @@ def unsketch(template: Any, s_vec: jnp.ndarray, seed: int) -> Any:
     for leaf, sd in zip(leaves, seeds):
         idx, sign = _leaf_hashes(leaf.shape, sd, r)
         out.append((s_vec[idx] * sign).astype(leaf.dtype))
+    return jax.tree.unflatten(treedef, out)
+
+
+def unsketch_stacked(template: Any, s_mat: jnp.ndarray, seed: int) -> Any:
+    """`unsketch` of each row of an (N, r) matrix: the (N,)-stacked tree,
+    gathered worker-major from the flat N*r vector as `sketch_stacked`
+    scatters into it."""
+    n_workers, r = s_mat.shape
+    flat = s_mat.reshape(-1)
+    offset = (r * jnp.arange(n_workers, dtype=jnp.int32))[:, None]
+    leaves, treedef, seeds = _leaf_seeds(template, seed)
+    out = []
+    for leaf, sd in zip(leaves, seeds):
+        idx, sign = _leaf_hashes(leaf.shape, sd, r)
+        vals = flat[idx.reshape(1, -1) + offset] * sign.reshape(1, -1)
+        out.append(vals.reshape((n_workers,) + leaf.shape)
+                   .astype(leaf.dtype))
     return jax.tree.unflatten(treedef, out)
 
 

@@ -32,7 +32,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.data import stream as stream_lib
-from repro.fed.sketch import sketch as _sketch, unsketch as _unsketch
+from repro.fed.sketch import (sketch as _sketch, sketch_stacked,
+                              unsketch as _unsketch, unsketch_stacked)
 from repro.models import config as mcfg
 from repro.models import transformer as tfm
 from repro.utils.tree import (tree_axpy, tree_dot, tree_norm_sq, tree_sub,
@@ -300,8 +301,8 @@ def eval_llm_cuts(hyper: FedHyper, cuts: LLMCutSet, z1, z2, z3, X2, X3,
         r = hyper.sketch_r
         s_z2 = _sketch(z2, seed, r)
         s_z3 = _sketch(z3, seed, r)
-        s_x2 = jax.vmap(lambda x: _sketch(x, seed, r))(X2)   # (N,r)
-        s_x3 = jax.vmap(lambda x: _sketch(x, seed, r))(X3)
+        s_x2 = sketch_stacked(X2, seed, r)   # (N,r)
+        s_x3 = sketch_stacked(X3, seed, r)
         val = val + cuts.a2 @ s_z2 + cuts.a3 @ s_z3 \
             + jnp.einsum("pnr,nr->p", cuts.b2, s_x2) \
             + jnp.einsum("pnr,nr->p", cuts.b3, s_x3)
@@ -321,7 +322,7 @@ def _contract_b(hyper: FedHyper, cuts: LLMCutSet, weights_np, block: str,
     b = getattr(cuts, block)
     if hyper.cut_mode == "sketch":
         coeff = jnp.einsum("np,pnr->nr", w, b)                  # (N,r)
-        return jax.vmap(lambda c: _unsketch(template, c, seed))(coeff)
+        return unsketch_stacked(template, coeff, seed)
     return jax.tree.map(
         lambda bb: jnp.einsum("np,pn...->n...", w,
                               bb.astype(jnp.float32)).astype(bb.dtype), b)
@@ -345,7 +346,7 @@ def _store_block(hyper: FedHyper, cur, grad_tree, slot, seed: int,
     if hyper.cut_mode == "sketch":
         r = hyper.sketch_r
         if per_worker:
-            s = jax.vmap(lambda g: _sketch(g, seed, r))(grad_tree)
+            s = sketch_stacked(grad_tree, seed, r)
         else:
             s = _sketch(grad_tree, seed, r)
         return cur.at[slot].set(s)

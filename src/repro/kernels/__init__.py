@@ -3,7 +3,8 @@
 <name>.py  : pl.pallas_call + explicit BlockSpec VMEM tiling
 cut_ad.py  : {mv, vm, outer} primitive closure (kernel-backed autodiff
              to arbitrary order for the cut contraction)
-ops.py     : jit'd public wrappers (interpret=True off-TPU)
+ops.py     : jit'd public wrappers (Mosaic on TPU, interpreter elsewhere)
+platform.py: the one place a kernel's `interpret` flag resolves
 ref.py     : pure-jnp oracles (the correctness source of truth)
 """
 from repro.kernels import cut_ad, ops, ref

@@ -37,6 +37,7 @@ cast cotangents back to the primal dtype.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.extend as jex
@@ -44,6 +45,7 @@ import jax.numpy as jnp
 from jax.interpreters import ad, batching, mlir
 
 from repro.kernels import cut_eval as _kern
+from repro.kernels.platform import resolve_interpret
 
 
 # --- kernel-backed impls (also the lowering + batching bodies) -------------
@@ -139,19 +141,22 @@ ad.primitive_transposes[outer_p] = _outer_transpose
 
 # --- public entry points ----------------------------------------------------
 
-def matvec(a, v, *, block_d: int = None, interpret: bool = True):
+def matvec(a, v, *, block_d: int = None, interpret: Optional[bool] = None):
     """(P,) = A @ v through the kernel, differentiable to any order."""
     block_d = _kern.BLOCK_D if block_d is None else block_d
-    return mv_p.bind(a, v, block_d=block_d, interpret=interpret)
+    return mv_p.bind(a, v, block_d=block_d,
+                     interpret=resolve_interpret(interpret))
 
 
-def vecmat(g, a, *, block_d: int = None, interpret: bool = True):
+def vecmat(g, a, *, block_d: int = None, interpret: Optional[bool] = None):
     """(D,) = g^T A through the kernel, differentiable to any order."""
     block_d = _kern.BLOCK_D if block_d is None else block_d
-    return vm_p.bind(g, a, block_d=block_d, interpret=interpret)
+    return vm_p.bind(g, a, block_d=block_d,
+                     interpret=resolve_interpret(interpret))
 
 
-def outer(x, y, *, block_d: int = None, interpret: bool = True):
+def outer(x, y, *, block_d: int = None, interpret: Optional[bool] = None):
     """(P, D) = x y^T through the kernel, differentiable to any order."""
     block_d = _kern.BLOCK_D if block_d is None else block_d
-    return outer_p.bind(x, y, block_d=block_d, interpret=interpret)
+    return outer_p.bind(x, y, block_d=block_d,
+                        interpret=resolve_interpret(interpret))

@@ -25,9 +25,13 @@ lives in VMEM, not an HBM-bound gather.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import resolve_interpret
 
 P_PAD = 8          # sublane alignment for the cut axis
 BLOCK_D = 2048     # lane-dim tile (multiple of 128)
@@ -69,7 +73,7 @@ def _matvec_kernel(a_ref, v_ref, out_ref):
     out_ref[...] += jnp.sum(a * v, axis=1, keepdims=True)  # (P_pad, 1)
 
 
-def matvec(a, v, *, block_d: int = BLOCK_D, interpret: bool = True):
+def matvec(a, v, *, block_d: int = BLOCK_D, interpret: Optional[bool] = None):
     """a: (P, D), v: (D,) -> (P,) f32 raw contraction A @ v."""
     p, d = a.shape
     p_pad = ((p + P_PAD - 1) // P_PAD) * P_PAD
@@ -84,7 +88,7 @@ def matvec(a, v, *, block_d: int = BLOCK_D, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((p_pad, 1), lambda j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((p_pad, 1), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(_pad_mat(a, p_pad, d_pad), _pad_row(v, d_pad))
     return out[:p, 0]
 
@@ -99,7 +103,7 @@ def _vecmat_kernel(g_ref, a_ref, out_ref):
     out_ref[...] = jnp.sum(g * a, axis=0, keepdims=True)   # (1, block_d)
 
 
-def vecmat(g, a, *, block_d: int = BLOCK_D, interpret: bool = True):
+def vecmat(g, a, *, block_d: int = BLOCK_D, interpret: Optional[bool] = None):
     """g: (P,), a: (P, D) -> (D,) f32 row-reduction g^T A.
 
     Each D tile is independent (the reduction runs over the resident P
@@ -117,7 +121,7 @@ def vecmat(g, a, *, block_d: int = BLOCK_D, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((1, block_d), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(_pad_col(g, p_pad), _pad_mat(a, p_pad, d_pad))
     return out[0, :d]
 
@@ -132,7 +136,7 @@ def _rank1_kernel(x_ref, y_ref, out_ref):
     out_ref[...] = x * y                        # (P_pad, block_d)
 
 
-def rank1(x, y, *, block_d: int = BLOCK_D, interpret: bool = True):
+def rank1(x, y, *, block_d: int = BLOCK_D, interpret: Optional[bool] = None):
     """x: (P,), y: (D,) -> (P, D) f32 rank-1 outer product x y^T."""
     p, d = x.shape[0], y.shape[0]
     p_pad = ((p + P_PAD - 1) // P_PAD) * P_PAD
@@ -147,13 +151,13 @@ def rank1(x, y, *, block_d: int = BLOCK_D, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((p_pad, block_d), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((p_pad, d_pad), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(_pad_col(x, p_pad), _pad_row(y, d_pad))
     return out[:p, :d]
 
 
 def cut_eval(a, v, c, active, *, block_d: int = BLOCK_D,
-             interpret: bool = True):
+             interpret: Optional[bool] = None):
     """a: (P, D), v: (D,), c: (P,), active: (P,) -> (P,) cut values.
 
     One streaming `matvec` kernel launch plus the O(P) jnp epilogue

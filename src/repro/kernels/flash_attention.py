@@ -14,11 +14,14 @@ index maps, so no KV replication is materialized.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 NEG_INF = -2.0 ** 20
 
@@ -67,7 +70,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q: (B,S,H,hd); k/v: (B,T,Hkv,hd) -> (B,S,H,hd).  S % block_q == 0
     and T % block_k == 0 (the ops wrapper pads)."""
     b, s, h, hd = q.shape
@@ -102,6 +105,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, 1), jnp.float32),    # denominator l
             pltpu.VMEM((block_q, hd), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

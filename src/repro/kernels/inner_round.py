@@ -41,6 +41,7 @@ the fused op stays differentiable to arbitrary order) live in
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +49,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.cut_eval import BLOCK_D, P_PAD, _clamp_block
+from repro.kernels.platform import resolve_interpret
 
 
 def _round_kernel(a_ref, v_ref, g_ref, mask_ref, c_ref, act_ref, s_ref,
@@ -106,7 +108,7 @@ def _round_kernel(a_ref, v_ref, g_ref, mask_ref, c_ref, act_ref, s_ref,
 def fused_cut_round(a, v, g_other, mask, c, active, s, gamma, *,
                     eta_z: float, eta_s: float, eta_dual: float,
                     rho2: float, block_d: int = BLOCK_D,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """One fused level-2 cut round.
 
     a: (P, D) cut matrix, v: (D,) flattened point at the OLD z2,
@@ -151,7 +153,7 @@ def fused_cut_round(a, v, g_other, mask, c, active, s, gamma, *,
             pltpu.VMEM((p_pad, 1), jnp.float32),    # mv accumulator
             pltpu.VMEM((p_pad, 1), jnp.float32),    # phase-0 weights
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a_p, row(v), row(g_other), row(mask), col(c), col(active), col(s),
       col(gamma))
     return v_new[0, :d], cv[:p, 0], s_new[:p, 0], gam_new[:p, 0]

@@ -14,10 +14,13 @@ sequential scan over chunks lives in the caller (ops.mlstm_sequence).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -74,7 +77,8 @@ def _mlstm_chunk_kernel(q_ref, k_ref, v_ref, li_ref, lf_ref,
     m_out_ref[0, 0] = m_new.astype(m_out_ref.dtype)
 
 
-def mlstm_chunk(q, k, v, li, lf, c, n, m, *, interpret: bool = True):
+def mlstm_chunk(q, k, v, li, lf, c, n, m, *,
+                interpret: Optional[bool] = None):
     """One chunk for all (batch, head) pairs.
 
     q/k/v: (B,H,L,hd); li/lf: (B,H,L,1); c: (B,H,hd,hd); n: (B,H,1,hd);
@@ -98,6 +102,6 @@ def mlstm_chunk(q, k, v, li, lf, c, n, m, *, interpret: bool = True):
             jax.ShapeDtypeStruct((b, h, 1, hd), jnp.float32),
             jax.ShapeDtypeStruct((b, h, 1, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, li, lf, c, n, m)
     return y, c2, n2, m2

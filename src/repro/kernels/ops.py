@@ -1,10 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-`interpret` defaults to True off-TPU (the kernel body executes in Python
-via the Pallas interpreter — bit-accurate semantics, no Mosaic); on a
-real TPU backend pass interpret=False (or rely on the default) to get
-the compiled kernels.  Models select kernels via `use_pallas` flags; the
-dry-run keeps the jnp oracles (Mosaic cannot AOT-lower on CPU).
+`interpret=None` (the default everywhere) resolves in
+`kernels.platform`: Mosaic on a TPU backend, the Pallas interpreter
+(bit-accurate semantics, kernel body in Python) elsewhere.  The cut
+path routes here; the models do not call the attention and mLSTM
+kernels (`ModelConfig.attn_impl` picks a jnp attention path).
 
 Autodiff contract for the cut path: `cut_eval` (and the fused inner
 round) are differentiable THROUGH the kernels to arbitrary order.  The
@@ -31,16 +31,13 @@ from repro.kernels import cut_eval as _cut_eval_mod
 from repro.kernels import flash_attention as _flash_mod
 from repro.kernels import inner_round as _round_mod
 from repro.kernels import mlstm_chunk as _mlstm_mod
+from repro.kernels.platform import on_tpu, resolve_interpret
 
 # trace-count pins (CI-style regression guards): incremented at TRACE
 # time, so a warm jit cache keeps them flat and an unroll regression
 # (e.g. mlstm_sequence falling back to a host chunk loop) multiplies
 # the per-trace count.
 TRACE_COUNTS: collections.Counter = collections.Counter()
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -72,10 +69,9 @@ def cut_eval(a, v, c, active, block_d: int = None,
     the tile to the (128-aligned) variable space, so small cut spaces
     aren't padded to a full paper-scale tile."""
     if impl is None:
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+        impl = "pallas" if on_tpu() else "ref"
     if impl == "ref":
         return (a.astype(jnp.float32) @ v.astype(jnp.float32) - c) * active
-    interpret = _default_interpret() if interpret is None else interpret
     if block_d is None:
         block_d = _cut_eval_mod.BLOCK_D
     raw = _cut_ad.matvec(a, v, block_d=block_d, interpret=interpret)
@@ -169,12 +165,12 @@ def fused_cut_round(a, v, g_other, mask, c, active, s, gamma, *,
     scan-of-jnp oracle `inner.rollout2` uses off-TPU.  impl=None
     auto-routes like `cut_eval`."""
     if impl is None:
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+        impl = "pallas" if on_tpu() else "ref"
     if impl == "ref":
         return _fused_round_ref(a, v, g_other, mask, c, active, s, gamma,
                                 eta_z=eta_z, eta_s=eta_s,
                                 eta_dual=eta_dual, rho2=rho2)
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     if block_d is None:
         block_d = _cut_eval_mod.BLOCK_D
     return _fused_round_p(block_d, interpret, eta_z, eta_s, eta_dual,
@@ -191,7 +187,6 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool = None):
     """Pads S/T to block multiples, calls the kernel, unpads."""
-    interpret = _default_interpret() if interpret is None else interpret
     b, s, h, hd = q.shape
     t = k.shape[1]
     bq = min(block_q, max(8, s))
@@ -219,7 +214,6 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def mlstm_chunk(q, k, v, li, lf, c, n, m, interpret: bool = None):
-    interpret = _default_interpret() if interpret is None else interpret
     return _mlstm_mod.mlstm_chunk(q, k, v, li, lf, c, n, m,
                                   interpret=interpret)
 

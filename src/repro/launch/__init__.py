@@ -1,2 +1,2 @@
-from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
+from repro.launch.mesh import (CHIP_PEAKS, chip_peaks,
                                make_debug_mesh, make_production_mesh)

@@ -48,7 +48,22 @@ def make_worker_mesh(n_shards: int, axis_name: str = "worker"):
     return Mesh(np.asarray(devices[:n_shards]), (axis_name,))
 
 
-# TPU v5e hardware constants used by the roofline analysis
-PEAK_FLOPS_BF16 = 197e12       # per chip
-HBM_BW = 819e9                 # bytes/s per chip
-ICI_BW = 50e9                  # bytes/s per link
+# Published per-chip peaks, keyed by `jax.Device.device_kind` (Google
+# Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s; the ICI figure is per link).  A kind missing here is an
+# error, never a default.
+CHIP_PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+# the chip the HLO dry-run estimates (`launch.roofline`) are sized for
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """Peak rates of one chip of `device_kind`; raises on an unknown kind."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak rates for device kind {device_kind!r}; known kinds: "
+            f"{sorted(CHIP_PEAKS)}") from None

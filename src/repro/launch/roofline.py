@@ -1,10 +1,12 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-Three terms per (arch x shape x mesh), in seconds (deliverable g):
+Three terms per (arch x shape x mesh), in seconds (deliverable g),
+over the peaks of the target chip (`launch.mesh.CHIP_PEAKS`, a TPU
+v5e: 197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s per ICI link):
 
-  compute    = HLO_FLOPs_total   / (chips * 197 TFLOP/s bf16)
-  memory     = HLO_bytes_total   / (chips * 819 GB/s HBM)
-  collective = collective_bytes  / (chips * 50 GB/s ICI link)
+  compute    = HLO_FLOPs_total   / (chips * peak bf16 FLOP/s)
+  memory     = HLO_bytes_total   / (chips * peak HBM bytes/s)
+  collective = collective_bytes  / (chips * ICI link bytes/s)
 
 Sourcing notes (measured behaviour of jax 0.8.2 / XLA CPU AOT):
   * `compiled.cost_analysis()` reports PER-DEVICE numbers after SPMD
@@ -28,7 +30,7 @@ import json
 import re
 from typing import Dict, Optional
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import TARGET_DEVICE_KIND, chip_peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
@@ -96,6 +98,7 @@ class RooflineReport:
     model_flops_total: float      # 6 * N_active * tokens
 
     def terms(self) -> Dict[str, float]:
+        pk = chip_peaks(TARGET_DEVICE_KIND)
         flops_total = self.hlo_flops_per_dev * self.chips
         # scan-mode undercount correction: the analytic model is the
         # floor (see module docstring); useful_ratio uses the corrected
@@ -103,12 +106,12 @@ class RooflineReport:
         flops_corr = max(flops_total, self.analytic_flops_total)
         coll = sum(v for k, v in self.coll_bytes.items() if k != "count")
         return {
-            "compute_s": flops_total / (self.chips * PEAK_FLOPS_BF16),
+            "compute_s": flops_total / (self.chips * pk["flops_bf16"]),
             "compute_corrected_s":
-                flops_corr / (self.chips * PEAK_FLOPS_BF16),
+                flops_corr / (self.chips * pk["flops_bf16"]),
             "memory_s": (self.hlo_bytes_per_dev * self.chips)
-                / (self.chips * HBM_BW),
-            "collective_s": coll / (self.chips * ICI_BW),
+                / (self.chips * pk["hbm_bw"]),
+            "collective_s": coll / (self.chips * pk["ici_bw"]),
             "useful_ratio": (self.model_flops_total
                              / max(flops_corr, 1.0)),
             "hbm_gb_per_dev": (self.arg_bytes + self.temp_bytes

@@ -136,9 +136,11 @@ def start_status_server(master, port: int):
 
 def spawn_tcp_workers(args, port: int):
     """One `repro.fed.runtime.worker` subprocess per worker id, pointed
-    at the master's bound port (each rebuilds the problem by name)."""
+    at the master's bound port (each rebuilds the problem by name).
+    Workers run on the host CPU: the master's process holds the
+    accelerator, and a child that reached for it would fail or hang."""
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     src_root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     env["PYTHONPATH"] = src_root + os.pathsep + env.get("PYTHONPATH", "")
@@ -360,4 +362,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

@@ -1,8 +1,10 @@
 """End-to-end training driver (deliverable b).
 
-Trains a reduced (or xlstm-125m-class) model with the federated trilevel
-AFTO step — or plain AdamW for comparison — on synthetic token streams,
-with checkpointing and loss logging.  Runs on CPU.
+Trains a model of the zoo at full width (or its `--reduced` variant)
+with the federated trilevel AFTO step — or plain AdamW for comparison —
+on synthetic token streams, with checkpointing and loss logging.  It
+runs on the default JAX backend: the full-width xlstm-125m step fits one
+TPU v5e (`chip_smoke.py` drives it there); `--reduced` is the CPU size.
 
 The default `--engine scan` drives `--scan-chunk`-sized chunks of the
 trajectory (default: `--log-every`, keeping the old behavior) inside
@@ -27,6 +29,7 @@ import dataclasses
 import json
 import time
 from functools import partial
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -222,7 +225,7 @@ def _chunk_loop(args, schedule, chunk, state, one_chunk, loss_at,
     (legacy z3-only when unset).  `start` > 0 continues a resumed run
     from that absolute step."""
     history = []
-    t0 = time.time()
+    t0 = time.perf_counter()
     for begin in range(start, args.steps, chunk):
         stop = min(begin + chunk, args.steps)
         state = one_chunk(state, begin, stop)
@@ -230,7 +233,7 @@ def _chunk_loop(args, schedule, chunk, state, one_chunk, loss_at,
                 or stop == args.steps):
             history.append({"step": stop, "loss": float(loss_at(state, stop)),
                             "sim_time": float(schedule.sim_time[stop - 1]),
-                            "host_s": round(time.time() - t0, 1),
+                            "host_s": time.perf_counter() - t0,
                             "cuts": float(jnp.sum(state.cuts.active))})
             print(json.dumps(history[-1]))
         if args.ckpt_dir and stop // args.ckpt_every > begin // args.ckpt_every:
@@ -385,7 +388,7 @@ def run_plain(cfg, args) -> dict:
     return {"history": history}
 
 
-def main():
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="xlstm-125m")
     ap.add_argument("--reduced", action="store_true",
@@ -435,17 +438,21 @@ def main():
                          "(--engine scan; bit-identical to the "
                          "uninterrupted run)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def main(argv: Optional[Sequence[str]] = None) -> dict:
+    args = parse_args(argv)
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduce_cfg(cfg)
     print(f"training {cfg.name} mode={args.mode} steps={args.steps}")
     if args.mode == "afto":
-        run_afto(cfg, args)
-    else:
-        run_plain(cfg, args)
+        return run_afto(cfg, args)
+    return run_plain(cfg, args)
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

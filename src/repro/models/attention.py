@@ -2,8 +2,9 @@
 KV cache for decode.
 
 The dense-math path here doubles as the flash-attention kernel's oracle
-(kernels/ref.py imports `attend`); the Pallas kernel replaces `attend` on
-real TPUs via the `use_pallas` flag in the model.
+(kernels/ref.py imports `attend`).  The models do not call the Pallas
+kernel: `ModelConfig.attn_impl` picks between this dense path and the
+chunked online-softmax one.
 """
 from __future__ import annotations
 

@@ -139,8 +139,8 @@ def param_count(cfg: ModelConfig) -> dict:
                     + di * (2 * cfg.ssm_d_state + 1) + di  # dt/B/C proj + A
                     + di * d)             # out_proj
         if b.mixer in ("mlstm", "slstm"):
-            # qkv + i/f gates + out
-            return d * 3 * h * hd + 2 * d * h + h * hd * d
+            # qkv + i/f gates + out + head norm
+            return d * 3 * h * hd + 2 * d * h + h * hd * d + h * hd
         raise ValueError(b.mixer)
 
     def mlp_params(b: BlockSpec) -> int:

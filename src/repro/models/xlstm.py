@@ -9,6 +9,12 @@ with a per-chunk max-stabilizer m.  Decode is the O(1) recurrent update.
 sLSTM keeps a per-head scalar-memory recurrence with exponential gating
 and a stabilizer state; it is inherently sequential, so training scans
 over time (cheap at xlstm-125m scale).
+
+Both cells end in a per-head layer norm (the xLSTM paper's group norm)
+before the output projection.  The mLSTM read-out divides by a
+per-(position, head) normalizer that can sit at its floor; the head norm
+cancels that scalar, without which the block Jacobian grows with the
+normalizer's inverse and the gradient grows geometrically with depth.
 """
 from __future__ import annotations
 
@@ -33,6 +39,7 @@ def mlstm_init(key, d, n_heads, head_dim, dtype):
         "wi": dense_init(ks[3], (d, n_heads), jnp.float32, fan_in=d),
         "wf": dense_init(ks[4], (d, n_heads), jnp.float32, fan_in=d),
         "fb": jnp.full((n_heads,), 3.0, jnp.float32),  # forget-bias ~ keep
+        "out_norm": jnp.zeros((n_heads, head_dim), dtype),
         "wo": dense_init(ks[5], (n_heads, head_dim, d), dtype,
                          fan_in=n_heads * head_dim),
     }
@@ -49,6 +56,7 @@ def slstm_init(key, d, n_heads, head_dim, dtype):
         "fb": jnp.full((n_heads,), 3.0, jnp.float32),
         "rz": dense_init(ks[4], (n_heads, head_dim, head_dim), dtype,
                          fan_in=head_dim),  # block-diag recurrent weights
+        "out_norm": jnp.zeros((n_heads, head_dim), dtype),
         "wo": dense_init(ks[5], (n_heads, head_dim, d), dtype,
                          fan_in=n_heads * head_dim),
     }
@@ -66,6 +74,16 @@ def init_slstm_state(batch, n_heads, head_dim):
             "n": jnp.zeros((batch, n_heads, head_dim), jnp.float32),
             "h": jnp.zeros((batch, n_heads, head_dim), jnp.float32),
             "m": jnp.full((batch, n_heads), -1e9, jnp.float32)}
+
+
+def head_norm(y, scale, eps: float = 1e-6):
+    """Per-head layer norm of a cell read-out y (..., H, hd), scaled by
+    (1 + scale) with scale (H, hd); returns f32."""
+    y = y.astype(jnp.float32)
+    mu = jnp.mean(y, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(y - mu), axis=-1, keepdims=True)
+    return (y - mu) * jax.lax.rsqrt(var + eps) \
+        * (1.0 + scale.astype(jnp.float32))
 
 
 # ---------------------------------------------------------------------------
@@ -158,7 +176,8 @@ def mlstm_apply(params, x, chunk: int = 256, state=None
     state, ys = jax.lax.scan(body, state,
                              (split(q), split(k), split(v),
                               split(li), split(lf)))
-    y = ys.transpose(1, 0, 2, 3, 4).reshape(b, s, h, hd).astype(x.dtype)
+    y = ys.transpose(1, 0, 2, 3, 4).reshape(b, s, h, hd)
+    y = head_norm(y, params["out_norm"]).astype(x.dtype)
     return jnp.einsum("bshk,hkd->bsd", y, params["wo"]), state
 
 
@@ -184,7 +203,8 @@ def mlstm_decode(params, x, state) -> Tuple[jnp.ndarray, dict]:
     num = jnp.einsum("bhd,bhde->bhe", qf, c)
     den = jnp.maximum(jnp.abs(jnp.einsum("bhd,bhd->bh", qf, n)),
                       jnp.exp(-m_new))
-    y = (num / den[..., None]).astype(x.dtype)[:, None]  # (B,1,H,hd)
+    y = head_norm(num / den[..., None], params["out_norm"])
+    y = y.astype(x.dtype)[:, None]                      # (B,1,H,hd)
     out = jnp.einsum("bshk,hkd->bsd", y, params["wo"])
     return out, {"c": c, "n": n, "m": m_new}
 
@@ -229,7 +249,8 @@ def slstm_apply(params, x, state=None) -> Tuple[jnp.ndarray, dict]:
         body, state,
         (z.transpose(1, 0, 2, 3), og.transpose(1, 0, 2, 3),
          li.transpose(1, 0, 2), lf.transpose(1, 0, 2)))
-    y = hs.transpose(1, 0, 2, 3).astype(x.dtype)       # (B,S,H,hd)
+    y = head_norm(hs.transpose(1, 0, 2, 3),
+                  params["out_norm"]).astype(x.dtype)   # (B,S,H,hd)
     return jnp.einsum("bshk,hkd->bsd", y, params["wo"]), state
 
 
@@ -241,5 +262,5 @@ def slstm_decode(params, x, state) -> Tuple[jnp.ndarray, dict]:
         jnp.einsum("bd,dh->bh", x[:, 0].astype(jnp.float32), params["wf"])
         + params["fb"])
     state = _slstm_step(params, state, z, og, li, lf)
-    y = state["h"].astype(x.dtype)[:, None]
+    y = head_norm(state["h"], params["out_norm"]).astype(x.dtype)[:, None]
     return jnp.einsum("bshk,hkd->bsd", y, params["wo"]), state

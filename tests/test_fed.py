@@ -15,13 +15,6 @@ from repro.utils.tree import tree_any_nan
 N, B, S = 4, 2, 32
 
 
-def _abstract_mesh(shape, names):
-    try:
-        return AbstractMesh(shape, names)
-    except TypeError:
-        pytest.skip("AbstractMesh(shape, axis_names) needs newer jax")
-
-
 def _setup(cut_mode="sketch"):
     cfg = reduced(get_config("llama3-8b"))
     hyper = FedHyper(n_workers=N, cut_mode=cut_mode, sketch_r=128,
@@ -61,7 +54,7 @@ def test_inactive_workers_frozen():
 
 
 def test_param_specs_rules():
-    mesh = _abstract_mesh((4, 4), ("data", "model"))
+    mesh = AbstractMesh((4, 4), ("data", "model"))
     cfg = reduced(get_config("mixtral-8x22b"))
     params = jax.eval_shape(lambda k: init_params(cfg, k),
                             jax.random.PRNGKey(0))
@@ -81,7 +74,7 @@ def test_param_specs_rules():
 
 
 def test_param_specs_divisibility_fallback():
-    mesh = _abstract_mesh((2, 16), ("data", "model"))
+    mesh = AbstractMesh((2, 16), ("data", "model"))
     cfg = reduced(get_config("xlstm-125m"))  # 4 heads < 16-way model axis
     params = jax.eval_shape(lambda k: init_params(cfg, k),
                             jax.random.PRNGKey(0))
@@ -94,7 +87,7 @@ def test_param_specs_divisibility_fallback():
 
 
 def test_worker_stack_axis():
-    mesh = _abstract_mesh((4, 4), ("data", "model"))
+    mesh = AbstractMesh((4, 4), ("data", "model"))
     cfg = reduced(get_config("llama3-8b"))
     params = jax.eval_shape(lambda k: init_params(cfg, k),
                             jax.random.PRNGKey(0))
@@ -149,3 +142,68 @@ def test_fed_state_checkpoint_roundtrip(tmp_path):
         np.testing.assert_array_equal(np.asarray(a, np.float32),
                                       np.asarray(b, np.float32))
     assert int(restored.t) == 1
+
+
+def _worker_stack(tree, n):
+    """n distinct per-worker copies of `tree`, stacked on a leading axis."""
+    return jax.tree.map(
+        lambda x: jnp.stack([x * (1.0 + 0.5 * j) + 0.01 * j
+                             for j in range(n)]), tree)
+
+
+def test_sketch_stacked_equals_per_worker_sketch():
+    from repro.fed.sketch import sketch, sketch_stacked
+
+    cfg = reduced(get_config("xlstm-125m"))
+    X3 = _worker_stack(init_params(cfg, jax.random.PRNGKey(0)), N)
+    one = jax.jit(lambda t: sketch(t, 2, 64))
+    got = np.asarray(jax.jit(lambda t: sketch_stacked(t, 2, 64))(X3))
+    want = np.stack([np.asarray(one(jax.tree.map(lambda x: x[j], X3)))
+                     for j in range(N)])
+    # the same compiled per-worker sum: bitwise equal
+    np.testing.assert_array_equal(got, want)
+    # the worker-vmapped form it replaces sums in another order
+    vmapped = np.asarray(jax.vmap(lambda x: sketch(x, 2, 64))(X3))
+    np.testing.assert_allclose(got, vmapped, rtol=0,
+                               atol=1e-5 * np.max(np.abs(want)))
+
+
+def test_unsketch_stacked_equals_per_worker_unsketch():
+    from repro.fed.sketch import unsketch, unsketch_stacked
+
+    cfg = reduced(get_config("xlstm-125m"))
+    template = init_params(cfg, jax.random.PRNGKey(0))
+    coeff = jax.random.normal(jax.random.PRNGKey(1), (N, 64))
+    got = unsketch_stacked(template, coeff, 2)
+    for j in range(N):
+        want = unsketch(template, coeff[j], 2)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(g[j]), np.asarray(w))
+
+
+def test_stacked_sketch_sharded_over_workers_matches_one_device():
+    """Sharded over a worker mesh, the worker-major scatter and gather
+    give the single-device values: each device handles its own rows."""
+    from jax.sharding import NamedSharding
+
+    from repro.fed.sketch import sketch_stacked, unsketch_stacked
+    from repro.launch.mesh import make_worker_mesh
+
+    cfg = reduced(get_config("xlstm-125m"))
+    template = init_params(cfg, jax.random.PRNGKey(0))
+    X3 = _worker_stack(template, N)
+    mesh = make_worker_mesh(N, axis_name="data")
+    by_worker = NamedSharding(mesh, P("data"))
+    sk = jax.jit(lambda t: sketch_stacked(t, 2, 64))
+    want = np.asarray(sk(X3))
+    got = np.asarray(sk(jax.device_put(X3, by_worker)))
+    # each bucket sums ~10^4 entries, in another order on the mesh
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-5 * np.max(np.abs(want)))
+
+    un = jax.jit(lambda c: unsketch_stacked(template, c, 2),
+                 out_shardings=by_worker)
+    coeff = jax.random.normal(jax.random.PRNGKey(1), (N, 64))
+    for g, w in zip(jax.tree.leaves(un(jax.device_put(coeff, by_worker))),
+                    jax.tree.leaves(unsketch_stacked(template, coeff, 2))):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -125,3 +125,41 @@ def test_mlstm_chunkwise_matches_decode():
     y_dec = jnp.concatenate(ys, axis=1)
     np.testing.assert_allclose(np.asarray(y_dec), np.asarray(y_chunk),
                                rtol=2e-3, atol=2e-3)
+
+
+def test_xlstm_head_norm_cancels_the_cell_normalizer():
+    """The mLSTM read-out is divided by a per-(position, head) scalar;
+    the head norm makes the block output independent of it."""
+    key = jax.random.PRNGKey(0)
+    y = jax.random.normal(key, (2, 5, 3, 16))
+    c = jnp.exp(jax.random.normal(jax.random.fold_in(key, 1), (2, 5, 3)))
+    scale = 0.1 * jax.random.normal(jax.random.fold_in(key, 2), (3, 16))
+    np.testing.assert_allclose(
+        np.asarray(xlstm_lib.head_norm(y * c[..., None], scale)),
+        np.asarray(xlstm_lib.head_norm(y, scale)), rtol=1e-4, atol=1e-4)
+
+
+def test_xlstm_gradient_does_not_grow_geometrically_with_depth():
+    """Doubling the xLSTM stack (12 -> 24 blocks) at init must not
+    multiply the gradient norm the way a geometric growth would: before
+    the head norm it rose 17x here (and 2x per block at full width)."""
+    import dataclasses
+
+    from repro.configs import get_config, reduced
+    from repro.models import transformer as tfm
+    from repro.models.config import Stage
+
+    base = reduced(get_config("xlstm-125m"))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0,
+                              base.vocab_size)
+
+    def grad_norm(repeats):
+        cfg = dataclasses.replace(
+            base, n_layers=6 * repeats,
+            stages=(Stage(base.stages[0].pattern, repeats),)).validate()
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        g = jax.grad(lambda p: tfm.train_loss(cfg, p, toks))(params)
+        return float(jnp.sqrt(sum(jnp.sum(jnp.square(x))
+                                  for x in jax.tree.leaves(g))))
+
+    assert grad_norm(4) < 4.0 * grad_norm(2)
