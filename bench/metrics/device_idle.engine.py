@@ -1,0 +1,10 @@
+"""device_idle.engine: the share of the traced window in which no
+operation ran on the device, in the engine cells.  Moves
+`fed_iters_per_s`."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if tr["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
