@@ -107,6 +107,7 @@ def local_f1_grads(problem: TrilevelProblem, X1, X2, X3) -> Tuple:
     return jax.vmap(f1_grads)(problem.data, X1, X2, X3)
 
 
+@jax.named_scope("afto_step")
 def afto_step_aux(problem: TrilevelProblem, hyper: Hyper, state: AFTOState,
                   active, axis: str = None) -> Tuple[AFTOState, dict]:
     """`afto_step` plus the step's cut-algebra intermediates.
@@ -238,6 +239,7 @@ def _bmask(active, x):
 # cut refresh (Eqs. 23-25, Alg. 1 middle block)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("cut_refresh")
 def cut_refresh(problem: TrilevelProblem, hyper: Hyper,
                 state: AFTOState) -> AFTOState:
     """Generate one I-layer and one II-layer mu-cut at the current point,

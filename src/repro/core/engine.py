@@ -66,6 +66,7 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import afto as afto_lib
 from repro.core import cuts as cuts_lib
@@ -153,7 +154,8 @@ def _cached_build(cache: Dict[tuple, tuple], key: tuple, build,
     cache[key] = hit
     return hit[0]
 
-# How many times each builder actually traced a new scan/sweep — the
+# How many times each build function traced a new scan/sweep (a cache
+# miss; in a profiler trace, the long `afto.build` span) — the
 # retrace regression tests assert this stays flat across warm calls
 # (the *_sharded counters cover the worker-mesh shard_map paths, the
 # *_streamed ones the in-scan data-stream paths: a stream's key is
@@ -243,6 +245,7 @@ def _make_step_body(problem: TrilevelProblem, hyper: Hyper,
                                                        axis)))
         st = jax.lax.cond(do_refresh, refresh, lambda s: s, st)
 
+        @jax.named_scope("gap_record")
         def write(h):
             # the gap reuses the step's flat cut operator + cut values;
             # a refresh rewrote the polytope, so recompute them there.
@@ -496,63 +499,69 @@ def run_scanned(problem: TrilevelProblem, hyper: Hyper, schedule: Schedule,
     stream = data if isinstance(data, Stream) else None
     if stream is not None:
         _check_stream(stream, hyper)
-    host_data = None if (data is None or stream is not None) else \
-        jax.tree.map(jnp.asarray, data)
     stream_spec = None if stream is None else stream.spec
     donate = state is None
-    if state is None:
-        # init_state aliases some buffers across fields (e.g. z3 and
-        # inner3.z3); donation requires distinct buffers, so copy once.
-        state = jax.tree.map(jnp.array, afto_lib.init_state(problem, hyper))
-    record_its, slots = record_slots(n_iterations, metrics_every)
-    n_records = len(record_its)
+    with TraceAnnotation("afto.init_state"):
+        if state is None:
+            # init_state aliases some buffers across fields (e.g. z3 and
+            # inner3.z3); donation requires distinct buffers, so copy once.
+            state = jax.tree.map(jnp.array,
+                                 afto_lib.init_state(problem, hyper))
 
-    keys = _metric_keys(problem, hyper, metrics_fn, state)
-    cache_key = (id(problem), id(metrics_fn), _hyper_key(hyper),
-                 n_iterations, metrics_every, donate, mesh,
-                 _data_key(data))
-    if mesh is None:
-        fn = _cached_build(
-            _CACHE, cache_key,
-            lambda: _build_scan(problem, hyper, metrics_fn, keys, donate,
-                                stream_spec=stream_spec),
-            (problem, metrics_fn, stream_spec))
-    else:
-        spec_i, spec_ii = state.cuts_i.spec, state.cuts_ii.spec
-        state = _shard_state(state, n_shards)
-        fn = _cached_build(
-            _CACHE, cache_key,
-            lambda: _build_scan_sharded(problem, hyper, metrics_fn, keys,
-                                        donate, mesh,
-                                        _state_specs(state),
-                                        stream_spec=stream_spec,
-                                        n_shards=n_shards),
-            (problem, metrics_fn, mesh, stream_spec))
+    with TraceAnnotation("afto.build"):
+        keys = _metric_keys(problem, hyper, metrics_fn, state)
+        cache_key = (id(problem), id(metrics_fn), _hyper_key(hyper),
+                     n_iterations, metrics_every, donate, mesh,
+                     _data_key(data))
+        if mesh is None:
+            fn = _cached_build(
+                _CACHE, cache_key,
+                lambda: _build_scan(problem, hyper, metrics_fn, keys,
+                                    donate, stream_spec=stream_spec),
+                (problem, metrics_fn, stream_spec))
+        else:
+            spec_i, spec_ii = state.cuts_i.spec, state.cuts_ii.spec
+            state = _shard_state(state, n_shards)
+            fn = _cached_build(
+                _CACHE, cache_key,
+                lambda: _build_scan_sharded(problem, hyper, metrics_fn,
+                                            keys, donate, mesh,
+                                            _state_specs(state),
+                                            stream_spec=stream_spec,
+                                            n_shards=n_shards),
+                (problem, metrics_fn, mesh, stream_spec))
 
-    hist0 = {k: jnp.zeros((n_records,), jnp.float32) for k in keys}
-    masks = jnp.asarray(schedule.active, jnp.float32)
-    key = None if stream is None else jnp.asarray(stream.key)
+    with TraceAnnotation("afto.stage"):
+        record_its, slots = record_slots(n_iterations, metrics_every)
+        hist0 = {k: jnp.zeros((len(record_its),), jnp.float32)
+                 for k in keys}
+        masks = jnp.asarray(schedule.active, jnp.float32)
+        slots = jnp.asarray(slots)
+        key = None if stream is None else jnp.asarray(stream.key)
+        if stream is not None:
+            data_arg = None
+        elif data is not None:
+            data_arg = jax.tree.map(jnp.asarray, data)
+        else:
+            data_arg = None if mesh is None else \
+                jax.tree.map(jnp.asarray, problem.data)
 
     t_start = time.perf_counter()
-    if mesh is None:
-        state, hist = fn(state, hist0, host_data, key, masks,
-                         jnp.asarray(slots))
-    else:
-        data_arg = None if stream is not None else (
-            host_data if host_data is not None
-            else jax.tree.map(jnp.asarray, problem.data))
-        state, hist = fn(state, hist0, data_arg, key, masks,
-                         jnp.asarray(slots))
-        state = _unshard_state(state, spec_i, spec_ii)
-    jax.block_until_ready(state)
+    with TraceAnnotation("afto.dispatch"):
+        state, hist = fn(state, hist0, data_arg, key, masks, slots)
+        if mesh is not None:
+            state = _unshard_state(state, spec_i, spec_ii)
+    with TraceAnnotation("afto.wait"):
+        jax.block_until_ready(state)
     elapsed = time.perf_counter() - t_start
 
-    history = {k: np.asarray(v) for k, v in hist.items()}
-    history["t"] = (record_its + 1).astype(np.float64)
-    history["sim_time"] = np.asarray(schedule.sim_time)[record_its]
-    history["max_staleness"] = np.asarray(
-        schedule.max_staleness)[record_its].astype(np.float64)
-    history["host_time"] = elapsed * (record_its + 1) / n_iterations
+    with TraceAnnotation("afto.fetch"):
+        history = {k: np.asarray(v) for k, v in hist.items()}
+        history["t"] = (record_its + 1).astype(np.float64)
+        history["sim_time"] = np.asarray(schedule.sim_time)[record_its]
+        history["max_staleness"] = np.asarray(
+            schedule.max_staleness)[record_its].astype(np.float64)
+        history["host_time"] = elapsed * (record_its + 1) / n_iterations
     return RunResult(state=state, history=history)
 
 
@@ -725,12 +734,12 @@ def run_swept(problem: TrilevelProblem, hyper: Hyper,
                 f"hyper field {name!r} is shape-determining and cannot "
                 "be swept; run separate sweeps instead")
     sweep_names = tuple(sorted(sweep_hypers))
-    sweep_vals = tuple(jnp.asarray(sweep_hypers[k]) for k in sweep_names)
-    for name, v in zip(sweep_names, sweep_vals):
-        if v.shape != (n_runs,):
+    for name in sweep_names:
+        shape = np.shape(sweep_hypers[name])
+        if shape != (n_runs,):
             raise ValueError(
                 f"sweep_hypers[{name!r}] must have shape ({n_runs},), "
-                f"got {v.shape}")
+                f"got {shape}")
 
     n_shards = None if mesh is None else _check_mesh(mesh, hyper)
     dkey = _data_key(data)
@@ -740,98 +749,110 @@ def run_swept(problem: TrilevelProblem, hyper: Hyper,
         _check_stream(stream, hyper)
         stream_spec = stream.spec
         data = None
-    if mesh is not None and states is None:
-        st0 = afto_lib.init_state(problem, hyper)
-        states = jax.tree.map(
-            lambda x: jnp.broadcast_to(
-                x[None], (n_runs,) + x.shape).astype(x.dtype), st0)
-    init_inside = states is None
-    if not init_inside:
-        # private copy: the swept dispatch donates its inputs
-        states = jax.tree.map(jnp.array, states)
     if data is not None:
-        data = jax.tree.map(jnp.asarray, data)
         for leaf in jax.tree.leaves(data):
-            if leaf.shape[:1] != (n_runs,):
+            if np.shape(leaf)[:1] != (n_runs,):
                 raise ValueError(
                     "swept data leaves need a leading (R,) axis")
+    with TraceAnnotation("afto.init_state"):
+        if mesh is not None and states is None:
+            st0 = afto_lib.init_state(problem, hyper)
+            states = jax.tree.map(
+                lambda x: jnp.broadcast_to(
+                    x[None], (n_runs,) + x.shape).astype(x.dtype), st0)
+        init_inside = states is None
+        if not init_inside:
+            # private copy: the swept dispatch donates its inputs
+            states = jax.tree.map(jnp.array, states)
 
-    record_its, slots = record_slots(n_iterations, metrics_every)
-    n_records = len(record_its)
-    if metrics_fn is None:
-        state_one = None           # _metric_keys won't trace anything
-    elif init_inside:
-        state_one = jax.eval_shape(
-            lambda: afto_lib.init_state(problem, hyper))
-    else:
-        state_one = jax.tree.map(lambda x: x[0], states)
-    keys = _metric_keys(problem, hyper, metrics_fn, state_one)
+    with TraceAnnotation("afto.build"):
+        if metrics_fn is None:
+            state_one = None       # _metric_keys won't trace anything
+        elif init_inside:
+            state_one = jax.eval_shape(
+                lambda: afto_lib.init_state(problem, hyper))
+        else:
+            state_one = jax.tree.map(lambda x: x[0], states)
+        keys = _metric_keys(problem, hyper, metrics_fn, state_one)
 
-    cache_key = (id(problem), id(metrics_fn), _hyper_key(hyper),
-                 sweep_names, dkey, init_inside, n_runs,
-                 n_iterations, metrics_every, mesh)
-    if mesh is not None:
-        spec_i = states.cuts_i.spec
-        spec_ii = states.cuts_ii.spec
-        states = dataclasses.replace(
-            states,
-            cuts_i=jax.vmap(lambda fc: cuts_lib.shard_cuts(fc, n_shards))(
-                states.cuts_i),
-            cuts_ii=jax.vmap(lambda fc: cuts_lib.shard_cuts(fc, n_shards))(
-                states.cuts_ii))
-        fn = _cached_build(
-            _SWEEP_CACHE, cache_key,
-            lambda: _build_sweep_sharded(
-                problem, hyper, metrics_fn, keys, sweep_names,
-                data is not None, mesh, _state_specs(states, lead=(None,)),
-                stream_spec=stream_spec, n_shards=n_shards),
-            (problem, metrics_fn, mesh, stream_spec))
-    else:
-        fn = _cached_build(
-            _SWEEP_CACHE, cache_key,
-            lambda: _build_sweep(problem, hyper, metrics_fn, keys,
-                                 sweep_names, data is not None,
-                                 init_inside, stream_spec=stream_spec),
-            (problem, metrics_fn, stream_spec))
+        cache_key = (id(problem), id(metrics_fn), _hyper_key(hyper),
+                     sweep_names, dkey, init_inside, n_runs,
+                     n_iterations, metrics_every, mesh)
+        if mesh is not None:
+            spec_i = states.cuts_i.spec
+            spec_ii = states.cuts_ii.spec
+            states = dataclasses.replace(
+                states,
+                cuts_i=jax.vmap(
+                    lambda fc: cuts_lib.shard_cuts(fc, n_shards))(
+                        states.cuts_i),
+                cuts_ii=jax.vmap(
+                    lambda fc: cuts_lib.shard_cuts(fc, n_shards))(
+                        states.cuts_ii))
+            fn = _cached_build(
+                _SWEEP_CACHE, cache_key,
+                lambda: _build_sweep_sharded(
+                    problem, hyper, metrics_fn, keys, sweep_names,
+                    data is not None, mesh,
+                    _state_specs(states, lead=(None,)),
+                    stream_spec=stream_spec, n_shards=n_shards),
+                (problem, metrics_fn, mesh, stream_spec))
+        else:
+            fn = _cached_build(
+                _SWEEP_CACHE, cache_key,
+                lambda: _build_sweep(problem, hyper, metrics_fn, keys,
+                                     sweep_names, data is not None,
+                                     init_inside, stream_spec=stream_spec),
+                (problem, metrics_fn, stream_spec))
 
-    hist0 = {k: jnp.zeros((n_runs, n_records), jnp.float32) for k in keys}
-    masks = jnp.asarray(
-        np.stack([s.active for s in schedules]), jnp.float32)
-    key = None if stream is None else jnp.asarray(stream.key)
+    with TraceAnnotation("afto.stage"):
+        sweep_vals = tuple(jnp.asarray(sweep_hypers[k])
+                           for k in sweep_names)
+        if data is not None:
+            data = jax.tree.map(jnp.asarray, data)
+        record_its, slots = record_slots(n_iterations, metrics_every)
+        hist0 = {k: jnp.zeros((n_runs, len(record_its)), jnp.float32)
+                 for k in keys}
+        masks = jnp.asarray(
+            np.stack([s.active for s in schedules]), jnp.float32)
+        slots = jnp.asarray(slots)
+        key = None if stream is None else jnp.asarray(stream.key)
+        if mesh is not None and stream is None and data is None:
+            data = jax.tree.map(jnp.asarray, problem.data)
 
     t_start = time.perf_counter()
-    if mesh is not None:
-        run_data = None if stream is not None else (
-            data if data is not None
-            else jax.tree.map(jnp.asarray, problem.data))
-        state, hist = fn(states, hist0, run_data, key, masks, sweep_vals,
-                         jnp.asarray(slots))
-        state = dataclasses.replace(
-            state,
-            cuts_i=jax.vmap(
-                lambda fc: cuts_lib.unshard_cuts(fc, spec_i))(state.cuts_i),
-            cuts_ii=jax.vmap(
-                lambda fc: cuts_lib.unshard_cuts(fc, spec_ii))(
-                    state.cuts_ii))
-    elif init_inside:
-        state, hist = fn(hist0, masks, sweep_vals, data, key,
-                         jnp.asarray(slots))
-    else:
-        state, hist = fn(states, hist0, masks, sweep_vals, data, key,
-                         jnp.asarray(slots))
-    jax.block_until_ready(state)
+    with TraceAnnotation("afto.dispatch"):
+        if mesh is not None:
+            state, hist = fn(states, hist0, data, key, masks, sweep_vals,
+                             slots)
+            state = dataclasses.replace(
+                state,
+                cuts_i=jax.vmap(
+                    lambda fc: cuts_lib.unshard_cuts(fc, spec_i))(
+                        state.cuts_i),
+                cuts_ii=jax.vmap(
+                    lambda fc: cuts_lib.unshard_cuts(fc, spec_ii))(
+                        state.cuts_ii))
+        elif init_inside:
+            state, hist = fn(hist0, masks, sweep_vals, data, key, slots)
+        else:
+            state, hist = fn(states, hist0, masks, sweep_vals, data, key,
+                             slots)
+    with TraceAnnotation("afto.wait"):
+        jax.block_until_ready(state)
     elapsed = time.perf_counter() - t_start
 
-    history = {k: np.asarray(v) for k, v in hist.items()}
-    history["t"] = (record_its + 1).astype(np.float64)
-    history["sim_time"] = np.stack(
-        [np.asarray(s.sim_time)[record_its] for s in schedules])
-    history["max_staleness"] = np.stack(
-        [np.asarray(s.max_staleness)[record_its].astype(np.float64)
-         for s in schedules])
-    # one dispatch covers R trajectories: charge each run elapsed/R
-    # (an approximation — the runs execute interleaved, not serially).
-    history["host_time"] = np.broadcast_to(
-        (elapsed / n_runs) * (record_its + 1) / n_iterations,
-        (n_runs, n_records)).copy()
+    with TraceAnnotation("afto.fetch"):
+        history = {k: np.asarray(v) for k, v in hist.items()}
+        history["t"] = (record_its + 1).astype(np.float64)
+        history["sim_time"] = np.stack(
+            [np.asarray(s.sim_time)[record_its] for s in schedules])
+        history["max_staleness"] = np.stack(
+            [np.asarray(s.max_staleness)[record_its].astype(np.float64)
+             for s in schedules])
+        # one dispatch covers R trajectories: charge each run elapsed/R
+        # (an approximation — the runs execute interleaved, not serially).
+        history["host_time"] = np.broadcast_to(
+            (elapsed / n_runs) * (record_its + 1) / n_iterations,
+            (n_runs, len(record_its))).copy()
     return SweepResult(state=state, history=history)

@@ -40,6 +40,7 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import afto as afto_lib
 from repro.core import engine as engine_lib
@@ -192,7 +193,20 @@ def run(spec, hyper: Optional[Hyper] = None, **kwargs):
 
 
 def run_spec(spec: RunSpec):
-    """Dispatch a `RunSpec` to its engine (the canonical entry's body)."""
+    """Dispatch a `RunSpec` to its engine (the canonical entry's body).
+
+    The whole call is the profiler span `afto.run`; the compiled engines
+    nest their phases inside it (README, "Observability")."""
+    with TraceAnnotation("afto.run"):
+        return _dispatch(spec)
+
+
+def _precompute(cfg: StragglerConfig, n_iterations: int) -> Schedule:
+    with TraceAnnotation("afto.schedule"):
+        return StragglerScheduler(cfg).precompute(n_iterations)
+
+
+def _dispatch(spec: RunSpec):
     problem, hyper = spec.problem, spec.hyper
     engine = spec.engine
     scheduler_cfg = spec.resolved_scheduler()
@@ -237,9 +251,8 @@ def run_spec(spec: RunSpec):
             seed_list = list(spec.seeds) if spec.seeds is not None \
                 else [scheduler_cfg.seed]
             schedules = [
-                StragglerScheduler(
-                    dataclasses.replace(scheduler_cfg, seed=s)
-                ).precompute(n_iterations)
+                _precompute(dataclasses.replace(scheduler_cfg, seed=s),
+                            n_iterations)
                 for s in seed_list]
         return engine_lib.run_swept(
             problem, hyper, schedules, metrics_fn=spec.metrics_fn,
@@ -249,8 +262,7 @@ def run_spec(spec: RunSpec):
     if engine == "scan":
         schedule = spec.schedule
         if schedule is None:
-            schedule = StragglerScheduler(scheduler_cfg).precompute(
-                n_iterations)
+            schedule = _precompute(scheduler_cfg, n_iterations)
         if spec.chunk_size is not None:
             return engine_lib.run_chunked(
                 problem, hyper, schedule, spec.chunk_size,

@@ -361,6 +361,7 @@ def make_cut_sharded(h0, grads, point, eps, mu, bound_alpha, axis):
 # the sharded cut refresh (Eqs. 23-25)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("cut_refresh")
 def cut_refresh_sharded(problem: TrilevelProblem, hyper: Hyper,
                         state: AFTOState, axis: str = WORKER_AXIS
                         ) -> AFTOState:

@@ -57,6 +57,16 @@ def _pad_col(x, p_pad: int):
     return jnp.zeros((p_pad, 1), x.dtype).at[:x.shape[0], 0].set(x)
 
 
+def _scoped(call, *args):
+    """`call(*args)`, the Mosaic call alone, in the name scope
+    `cut_kernel` that a profile is read by.  XLA names a custom call
+    after its innermost scope, so `cut_eval` sits inside it: every cut
+    kernel call is `cut_eval.N` in the compiled program, under `vmap`,
+    JVP and transpose alike."""
+    with jax.named_scope("cut_kernel"), jax.named_scope("cut_eval"):
+        return call(*args)
+
+
 # ---------------------------------------------------------------------------
 # forward: matvec  (P,) = A @ v
 # ---------------------------------------------------------------------------
@@ -79,7 +89,7 @@ def matvec(a, v, *, block_d: int = BLOCK_D, interpret: Optional[bool] = None):
     p_pad = ((p + P_PAD - 1) // P_PAD) * P_PAD
     block_d = _clamp_block(d, block_d)
     d_pad = ((d + block_d - 1) // block_d) * block_d
-    out = pl.pallas_call(
+    out = _scoped(pl.pallas_call(
         _matvec_kernel,
         grid=(d_pad // block_d,),
         in_specs=[
@@ -89,7 +99,7 @@ def matvec(a, v, *, block_d: int = BLOCK_D, interpret: Optional[bool] = None):
         out_specs=pl.BlockSpec((p_pad, 1), lambda j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((p_pad, 1), jnp.float32),
         interpret=resolve_interpret(interpret),
-    )(_pad_mat(a, p_pad, d_pad), _pad_row(v, d_pad))
+    ), _pad_mat(a, p_pad, d_pad), _pad_row(v, d_pad))
     return out[:p, 0]
 
 
@@ -112,7 +122,7 @@ def vecmat(g, a, *, block_d: int = BLOCK_D, interpret: Optional[bool] = None):
     p_pad = ((p + P_PAD - 1) // P_PAD) * P_PAD
     block_d = _clamp_block(d, block_d)
     d_pad = ((d + block_d - 1) // block_d) * block_d
-    out = pl.pallas_call(
+    out = _scoped(pl.pallas_call(
         _vecmat_kernel,
         grid=(d_pad // block_d,),
         in_specs=[
@@ -122,7 +132,7 @@ def vecmat(g, a, *, block_d: int = BLOCK_D, interpret: Optional[bool] = None):
         out_specs=pl.BlockSpec((1, block_d), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
         interpret=resolve_interpret(interpret),
-    )(_pad_col(g, p_pad), _pad_mat(a, p_pad, d_pad))
+    ), _pad_col(g, p_pad), _pad_mat(a, p_pad, d_pad))
     return out[0, :d]
 
 
@@ -142,7 +152,7 @@ def rank1(x, y, *, block_d: int = BLOCK_D, interpret: Optional[bool] = None):
     p_pad = ((p + P_PAD - 1) // P_PAD) * P_PAD
     block_d = _clamp_block(d, block_d)
     d_pad = ((d + block_d - 1) // block_d) * block_d
-    out = pl.pallas_call(
+    out = _scoped(pl.pallas_call(
         _rank1_kernel,
         grid=(d_pad // block_d,),
         in_specs=[
@@ -152,7 +162,7 @@ def rank1(x, y, *, block_d: int = BLOCK_D, interpret: Optional[bool] = None):
         out_specs=pl.BlockSpec((p_pad, block_d), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((p_pad, d_pad), jnp.float32),
         interpret=resolve_interpret(interpret),
-    )(_pad_col(x, p_pad), _pad_row(y, d_pad))
+    ), _pad_col(x, p_pad), _pad_row(y, d_pad))
     return out[:p, :d]
 
 
