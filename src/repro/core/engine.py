@@ -25,7 +25,10 @@ matrix shards by worker columns (a tree of stacked blocks does not).
 body over a leading run axis R (stacked initial states, stacked schedule
 masks, per-run data and sweepable hyper scalars) so a whole benchmark
 sweep — every (seed, method) cell — is ONE donated XLA dispatch
-returning (R,)-leading states and histories.
+returning (R,)-leading states and histories.  A vmapped `lax.cond` on a
+per-run predicate would run both branches every iteration, so the sweep
+gates the refresh by a cond over the run axis instead: it runs at the
+iterations where any run refreshes, and each run keeps its own result.
 
 Both accept `mesh=` (a `jax.sharding.Mesh` with a "worker" axis) and
 then run shard_map-distributed: worker-stacked state, per-worker data,
@@ -194,15 +197,46 @@ def _check_stream(stream: Stream, hyper: Hyper) -> None:
 _STATIC_HYPER_FIELDS = frozenset({"n_workers", "p_max", "k_inner", "d1"})
 
 
+# vmap axis name of the sweeps' run axis
+_RUN_AXIS = "run"
+
+
+def _run_gated_cond(pred, run_axis: Optional[str], true_fn, false_fn,
+                    *operands):
+    """`lax.cond(pred, true_fn, false_fn, *operands)` for a per-run `pred`.
+
+    Under `jax.vmap` a batched predicate turns `lax.cond` into a select
+    that executes both branches for every run.  With `run_axis` (the
+    vmap axis name) the branch is instead gated on whether ANY run takes
+    it — a pmax over the run axis, unbatched, so a real cond — and
+    inside, each run keeps its own branch's result by a `where`.  Runs
+    out of phase then pay the branch at the union of their iterations.
+    """
+    if run_axis is None:
+        return jax.lax.cond(pred, true_fn, false_fn, *operands)
+    any_pred = jax.lax.pmax(pred.astype(jnp.int32), run_axis) > 0
+
+    def gated(*ops):
+        return jax.tree.map(lambda a, b: jnp.where(pred, a, b),
+                            true_fn(*ops), false_fn(*ops))
+
+    return jax.lax.cond(any_pred, gated, false_fn, *operands)
+
+
 def _make_step_body(problem: TrilevelProblem, hyper: Hyper,
                     metrics_fn: Optional[Callable], keys,
                     axis: Optional[str] = None,
-                    stream_spec=None, n_shards: Optional[int] = None):
+                    stream_spec=None, n_shards: Optional[int] = None,
+                    run_axis: Optional[str] = None):
     """The per-iteration scan body shared by run_scanned and run_swept.
 
     axis: worker mesh axis when tracing inside the shard_map'd engines —
     `problem`/state/mask then carry this shard's workers only and the
     refresh dispatches to the sharded cut generation.
+
+    run_axis: the sweeps' vmap axis over runs; the refresh and the gap
+    record's refreshed cut operator are then gated by `_run_gated_cond`.
+    None (the scan engines) traces plain `lax.cond`s.
 
     stream_spec: when set, the carry grows a (constant) stream key and
     each iteration's `problem.data` is synthesized in-scan from fold-in
@@ -243,14 +277,15 @@ def _make_step_body(problem: TrilevelProblem, hyper: Hyper,
             if axis is None else
             (lambda s: sharded_lib.cut_refresh_sharded(prob, hyper, s,
                                                        axis)))
-        st = jax.lax.cond(do_refresh, refresh, lambda s: s, st)
+        st = _run_gated_cond(do_refresh, run_axis, refresh, lambda s: s,
+                             st)
 
         @jax.named_scope("gap_record")
         def write(h):
             # the gap reuses the step's flat cut operator + cut values;
             # a refresh rewrote the polytope, so recompute them there.
-            aux = jax.lax.cond(
-                do_refresh,
+            aux = _run_gated_cond(
+                do_refresh, run_axis,
                 lambda s, _a: stat_lib.make_gap_aux(prob, hyper, s,
                                                     axis=axis),
                 lambda _s, a: a, st, step_aux)
@@ -582,7 +617,8 @@ def _build_sweep(problem: TrilevelProblem, hyper: Hyper,
             hyper, **dict(zip(sweep_names, sweep_vals))) \
             if sweep_names else hyper
         step_body = _make_step_body(prob, hyp, metrics_fn, keys,
-                                    stream_spec=stream_spec)
+                                    stream_spec=stream_spec,
+                                    run_axis=_RUN_AXIS)
         carry = (st, hist) if stream_spec is None else (st, hist, key)
         carry, _ = jax.lax.scan(step_body, carry, (masks, slots))
         return carry[0], carry[1]
@@ -592,7 +628,8 @@ def _build_sweep(problem: TrilevelProblem, hyper: Hyper,
         # with run_scanned); per-run variation comes from the schedules
         return jax.vmap(
             one_run,
-            in_axes=(0, 0, 0, 0, 0 if has_data else None, None, None))(
+            in_axes=(0, 0, 0, 0, 0 if has_data else None, None, None),
+            axis_name=_RUN_AXIS)(
                 st, hist, masks, sweep_vals, data, key, slots)
 
     if not init_inside:
@@ -631,7 +668,7 @@ def _build_sweep_sharded(problem: TrilevelProblem, hyper: Hyper,
             if sweep_names else hyper
         step_body = _make_step_body(prob, hyp, metrics_fn, keys,
                                     axis=axis, stream_spec=stream_spec,
-                                    n_shards=n_shards)
+                                    n_shards=n_shards, run_axis=_RUN_AXIS)
         carry = (st, hist) if stream_spec is None else (st, hist, key)
         carry, _ = jax.lax.scan(step_body, carry, (masks, slots))
         return carry[0], carry[1]
@@ -639,9 +676,12 @@ def _build_sweep_sharded(problem: TrilevelProblem, hyper: Hyper,
     def sweep_all(st, hist, data, key, masks, sweep_vals, slots):
         # (R, 1, P, D_loc) cut blocks -> (R, P, D_loc) inside the shard
         st = _map_cuts(st, lambda a: a[:, 0])
+        # the run axis is local to the shard and st.t is replicated over
+        # workers, so every shard opens the refresh gate together
         st, hist = jax.vmap(
             one_run,
-            in_axes=(0, 0, 0, 0, 0 if has_data else None, None, None))(
+            in_axes=(0, 0, 0, 0, 0 if has_data else None, None, None),
+            axis_name=_RUN_AXIS)(
                 st, hist, masks, sweep_vals, data, key, slots)
         return _map_cuts(st, lambda a: a[:, None]), hist
 
@@ -691,12 +731,15 @@ def run_swept(problem: TrilevelProblem, hyper: Hyper,
       sweep_hypers dict of Hyper field name -> (R,) values, threaded
                    into the traced step per run.  Shape-determining
                    fields (n_workers/p_max/k_inner/d1) stay static and
-                   cannot be swept.  Sweeping t_pre/t1 is allowed but
-                   costs: the refresh predicate becomes per-run, the
-                   vmapped `lax.cond` lowers to a select, and the full
-                   `cut_refresh` (inner rollouts + second-order grads)
-                   executes every iteration for every run — correct
-                   results, single-run-engine perf lost.
+                   cannot be swept.  t_pre/t1 may be swept.
+
+    The refresh runs at the iterations where any run refreshes: its
+    predicate is per-run (it reads each run's `state.t`, and swept
+    t_pre/t1), so it is gated by a `lax.cond` on a max over the run axis
+    and each run keeps its own result by a `where`.  Runs in phase
+    refresh every t_pre-th iteration, as one scanned run does; runs out
+    of phase (stacked `states` at different `t`, swept t_pre/t1) pay the
+    refresh at the union of their refresh iterations.
 
     History layout: per-run keys (gap_sq, n_cuts_*, sim_time,
     max_staleness, host_time, metrics_fn keys) are (R, n_records)

@@ -351,3 +351,87 @@ def test_swept_respects_caller_states_and_data():
                                    rtol=2e-4, atol=1e-6)
     assert all(np.all(np.isfinite(np.asarray(x)))
                for x in jax.tree.leaves(states))
+
+
+def _count_conds(jaxpr) -> int:
+    """`cond` equations in a jaxpr and every sub-jaxpr it holds."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "cond"
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                    n += _count_conds(sub)
+    return n
+
+
+def test_swept_refresh_stays_gated():
+    """The sweep keeps the refresh under a real cond: a vmapped cond on
+    the per-run predicate would lower to a select of both branches."""
+    from repro.core import afto as afto_lib
+
+    prob = make_quadratic_problem()
+    hyper = _hyper()
+    sched = _schedules(12, (0,))[0]
+    keys = engine_lib._metric_keys(prob, hyper, None, None)
+    _, slots = record_slots(12, 6)
+    slots = jnp.asarray(slots)
+    masks = jnp.asarray(sched.active, jnp.float32)
+    hist = {k: jnp.zeros((3,), jnp.float32) for k in keys}
+
+    scan = engine_lib._build_scan(prob, hyper, None, keys, donate=False)
+    scan_jaxpr = jax.make_jaxpr(scan)(
+        afto_lib.init_state(prob, hyper), hist, None, None, masks, slots)
+    sweep = engine_lib._build_sweep(prob, hyper, None, keys, (), False,
+                                    init_inside=True)
+    sweep_jaxpr = jax.make_jaxpr(sweep)(
+        {k: v[None] for k, v in hist.items()}, masks[None], (), None,
+        None, slots)
+    n_scan, n_sweep = _count_conds(scan_jaxpr), _count_conds(sweep_jaxpr)
+    assert n_scan >= 3
+    assert n_sweep >= n_scan
+
+
+@pytest.mark.parametrize("case", ["stacked_t", "swept_t_pre"])
+def test_swept_out_of_phase_runs_refresh_on_their_own_t(case):
+    """Runs whose refresh iterations differ — stacked states at different
+    t, or a swept t_pre — each match run_scanned from the same state: the
+    gate opens at the union of their refresh iterations and the per-run
+    `where` keeps every other run's state as it was."""
+    from repro.core import afto as afto_lib
+    from repro.utils.tree import tree_stack
+
+    prob = make_quadratic_problem()
+    hyper = _hyper()
+    if case == "stacked_t":
+        n_runs, hypers, sweep = 3, [hyper] * 3, None
+        pre = _schedules(2, (7,))[0]
+        init = afto_lib.init_state(prob, hyper)
+        starts = [init] + [run_scanned(prob, hyper, pre.slice(0, r),
+                                       state=init).state
+                           for r in (1, 2)]
+        assert [int(s.t) for s in starts] == [0, 1, 2]
+    else:
+        t_pres = [5, 10]
+        n_runs = len(t_pres)
+        hypers = [dataclasses.replace(hyper, t_pre=t) for t in t_pres]
+        sweep = {"t_pre": t_pres}
+        starts = [afto_lib.init_state(prob, hyper)] * n_runs
+    scheds = _schedules(40, tuple(range(n_runs)))
+    swept = run_swept(prob, hyper, scheds, states=tree_stack(starts),
+                      sweep_hypers=sweep, metrics_every=10)
+    for r in range(n_runs):
+        single = run_scanned(prob, hypers[r], scheds[r], state=starts[r],
+                             metrics_every=10)
+        row = swept.run(r)
+        for a, b in zip(jax.tree.leaves(single.state),
+                        jax.tree.leaves(row.state)):
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32), np.asarray(b, np.float32),
+                rtol=2e-5, atol=1e-6)
+        np.testing.assert_allclose(single.history["gap_sq"],
+                                   row.history["gap_sq"],
+                                   rtol=2e-4, atol=1e-6)
+        assert list(single.history["n_cuts_ii"]) == \
+            list(row.history["n_cuts_ii"])
