@@ -4,8 +4,7 @@
 The straggler scheduler is a seeded host-side simulation with no feedback
 from the optimization state, so its entire arrival process can be
 materialized up front (`StragglerScheduler.precompute`) and the
-T-iteration trajectory of Alg. 1 driven inside a single donated-buffer
-`jax.lax.scan`:
+T-iteration trajectory of Alg. 1 driven inside a single `jax.lax.scan`:
 
   * `afto_step` every master iteration (Eqs. 16-21),
   * `cut_refresh` via `lax.cond` on every t_pre-th iteration with
@@ -44,7 +43,7 @@ Both engines also accept `data=`: replacement `problem.data` arrays
 datasets of one layout), or a `repro.data.stream.Stream`, in which case
 every iteration's worker batches are SYNTHESIZED INSIDE the scan body
 from fold-in PRNG keys (`stream.batch_at(spec, key, state.stale.t_hat,
-...)`).  The stream's base key rides the donated carry untouched and
+...)`).  The stream's base key rides the carry untouched and
 each worker's row folds on its absolute consumption time (the carried
 pre-step `state.stale.t_hat`), so any chunk partition of a trajectory
 (state-continued `run_scanned` calls) sees the bit-identical batch
@@ -59,6 +58,14 @@ body); host-callback metrics still work through the eager path of
 Compiled trajectories are cached per (problem, hyper, metrics_fn,
 schedule length, record layout), so repeated runs — e.g. the AFTO/SFTO
 sweeps in the benchmarks — pay tracing + compilation once.
+
+A fresh solve (no state passed) builds its initial state, its history
+buffers and its record slots inside the compiled program, so a warm
+one is a single dispatch whose only input array is the schedule's
+masks (`INIT_COUNTS` counts where fresh states were built).
+`run_scanned` uses a passed state as given and does not donate it.  The
+worker-mesh engines build a fresh state on the host, and `run_swept`
+copies passed states for its donated dispatch.
 """
 from __future__ import annotations
 
@@ -167,6 +174,11 @@ BUILD_COUNTS = {"scan": 0, "sweep": 0, "scan_sharded": 0,
                 "sweep_sharded": 0, "scan_streamed": 0,
                 "sweep_streamed": 0, "scan_sharded_streamed": 0,
                 "sweep_sharded_streamed": 0}
+
+# Where `run_scanned` got a fresh solve's initial state: "program" when
+# its compiled scan built it, "host" when the host built and copied one
+# (the sharded scan).  A passed state counts in neither.
+INIT_COUNTS = {"program": 0, "host": 0}
 
 
 def _data_key(data):
@@ -309,10 +321,19 @@ def _make_step_body(problem: TrilevelProblem, hyper: Hyper,
 
 def _build_scan(problem: TrilevelProblem, hyper: Hyper,
                 metrics_fn: Optional[Callable], keys, donate: bool,
-                stream_spec=None):
+                stream_spec=None, init_inside: bool = False,
+                metrics_every: Optional[int] = None):
+    """The compiled scan: `(state, hist0, data, key, masks, slots)` ->
+    (final state, history).
+
+    init_inside: a fresh solve's program, `(data, key, masks)` -> the
+    same.  It builds `init_state`, the zeroed history buffers and the
+    record slots of `metrics_every` itself, so a warm fresh solve is one
+    dispatch with the masks as its only input array (and the stream key,
+    where there is one)."""
     BUILD_COUNTS["scan_streamed" if stream_spec else "scan"] += 1
 
-    def scan_all(st, hist, data, key, masks, slots):
+    def trajectory(st, hist, data, key, masks, slots):
         prob = problem if data is None else \
             dataclasses.replace(problem, data=data)
         step_body = _make_step_body(prob, hyper, metrics_fn, keys,
@@ -321,13 +342,32 @@ def _build_scan(problem: TrilevelProblem, hyper: Hyper,
         carry, _ = jax.lax.scan(step_body, carry, (masks, slots))
         return carry[0], carry[1]
 
+    # both programs are named `scan_all`: traces find them by that name
+    if init_inside:
+        def scan_all(data, key, masks):
+            record_its, slots = record_slots(masks.shape[0], metrics_every)
+            hist = {k: jnp.zeros((len(record_its),), jnp.float32)
+                    for k in keys}
+            return trajectory(afto_lib.init_state(problem, hyper), hist,
+                              data, key, masks, slots)
+
+        return jax.jit(scan_all)
+
+    def scan_all(st, hist, data, key, masks, slots):
+        return trajectory(st, hist, data, key, masks, slots)
+
     donate_argnums = (0, 1) if donate else ()
     return jax.jit(scan_all, donate_argnums=donate_argnums)
 
 
-def _metric_keys(problem, hyper, metrics_fn, state):
+def _metric_keys(problem, hyper, metrics_fn, state=None):
+    """History keys: the built-in records, then `metrics_fn`'s, traced
+    on `state` (the abstract `init_state` when None)."""
     keys = ["gap_sq", "n_cuts_i", "n_cuts_ii"]
     if metrics_fn is not None:
+        if state is None:
+            state = jax.eval_shape(lambda: afto_lib.init_state(problem,
+                                                               hyper))
         extra = jax.eval_shape(metrics_fn, state)
         keys += [k for k in extra if k not in keys]
     return tuple(keys)
@@ -505,6 +545,15 @@ def run_scanned(problem: TrilevelProblem, hyper: Hyper, schedule: Schedule,
     per-iteration host timestamps do not exist inside a compiled
     trajectory.
 
+    state: where to start.  None (a fresh solve) builds `init_state`,
+    the history buffers and the record slots inside the compiled
+    program: a warm call is one dispatch, the masks its only input
+    array, and the history comes back in one transfer.  A passed state
+    is used as given and not donated (the caller's buffers stay
+    readable); its program takes state, history buffers and slots as
+    arguments.  With `mesh` a fresh state is still built and sharded on
+    the host.  `INIT_COUNTS` counts which way each fresh state went.
+
     mesh: a `jax.sharding.Mesh` with a "worker" axis distributes the
     federation via shard_map — worker-stacked state, schedule-mask
     columns, per-worker data and the polytope b-columns partition over
@@ -535,43 +584,47 @@ def run_scanned(problem: TrilevelProblem, hyper: Hyper, schedule: Schedule,
     if stream is not None:
         _check_stream(stream, hyper)
     stream_spec = None if stream is None else stream.spec
-    donate = state is None
+    fresh = state is None
+    init_inside = fresh and mesh is None
     with TraceAnnotation("afto.init_state"):
-        if state is None:
+        if init_inside:
+            INIT_COUNTS["program"] += 1
+        elif fresh:
             # init_state aliases some buffers across fields (e.g. z3 and
             # inner3.z3); donation requires distinct buffers, so copy once.
             state = jax.tree.map(jnp.array,
                                  afto_lib.init_state(problem, hyper))
+            INIT_COUNTS["host"] += 1
 
     with TraceAnnotation("afto.build"):
-        keys = _metric_keys(problem, hyper, metrics_fn, state)
         cache_key = (id(problem), id(metrics_fn), _hyper_key(hyper),
-                     n_iterations, metrics_every, donate, mesh,
+                     n_iterations, metrics_every, fresh, mesh,
                      _data_key(data))
-        if mesh is None:
-            fn = _cached_build(
-                _CACHE, cache_key,
-                lambda: _build_scan(problem, hyper, metrics_fn, keys,
-                                    donate, stream_spec=stream_spec),
-                (problem, metrics_fn, stream_spec))
-        else:
+        if mesh is not None:
             spec_i, spec_ii = state.cuts_i.spec, state.cuts_ii.spec
-            state = _shard_state(state, n_shards)
-            fn = _cached_build(
-                _CACHE, cache_key,
-                lambda: _build_scan_sharded(problem, hyper, metrics_fn,
-                                            keys, donate, mesh,
-                                            _state_specs(state),
-                                            stream_spec=stream_spec,
-                                            n_shards=n_shards),
-                (problem, metrics_fn, mesh, stream_spec))
+            sharded = _shard_state(state, n_shards)
+
+        def build():
+            # the history keys ride the cache entry: a hit traces nothing
+            keys = _metric_keys(problem, hyper, metrics_fn, state)
+            if mesh is None:
+                return _build_scan(problem, hyper, metrics_fn, keys, fresh,
+                                   stream_spec=stream_spec,
+                                   init_inside=init_inside,
+                                   metrics_every=metrics_every), keys
+            return _build_scan_sharded(problem, hyper, metrics_fn, keys,
+                                       fresh, mesh, _state_specs(sharded),
+                                       stream_spec=stream_spec,
+                                       n_shards=n_shards), keys
+
+        fn, keys = _cached_build(_CACHE, cache_key, build,
+                                 (problem, metrics_fn, mesh, stream_spec))
+        if mesh is not None:
+            state = sharded
 
     with TraceAnnotation("afto.stage"):
         record_its, slots = record_slots(n_iterations, metrics_every)
-        hist0 = {k: jnp.zeros((len(record_its),), jnp.float32)
-                 for k in keys}
         masks = jnp.asarray(schedule.active, jnp.float32)
-        slots = jnp.asarray(slots)
         key = None if stream is None else jnp.asarray(stream.key)
         if stream is not None:
             data_arg = None
@@ -580,10 +633,17 @@ def run_scanned(problem: TrilevelProblem, hyper: Hyper, schedule: Schedule,
         else:
             data_arg = None if mesh is None else \
                 jax.tree.map(jnp.asarray, problem.data)
+        if not init_inside:
+            hist0 = {k: jnp.zeros((len(record_its),), jnp.float32)
+                     for k in keys}
+            slots = jnp.asarray(slots)
 
     t_start = time.perf_counter()
     with TraceAnnotation("afto.dispatch"):
-        state, hist = fn(state, hist0, data_arg, key, masks, slots)
+        if init_inside:
+            state, hist = fn(data_arg, key, masks)
+        else:
+            state, hist = fn(state, hist0, data_arg, key, masks, slots)
         if mesh is not None:
             state = _unshard_state(state, spec_i, spec_ii)
     with TraceAnnotation("afto.wait"):
@@ -591,7 +651,7 @@ def run_scanned(problem: TrilevelProblem, hyper: Hyper, schedule: Schedule,
     elapsed = time.perf_counter() - t_start
 
     with TraceAnnotation("afto.fetch"):
-        history = {k: np.asarray(v) for k, v in hist.items()}
+        history = jax.device_get(hist)
         history["t"] = (record_its + 1).astype(np.float64)
         history["sim_time"] = np.asarray(schedule.sim_time)[record_its]
         history["max_staleness"] = np.asarray(
@@ -809,13 +869,9 @@ def run_swept(problem: TrilevelProblem, hyper: Hyper,
             states = jax.tree.map(jnp.array, states)
 
     with TraceAnnotation("afto.build"):
-        if metrics_fn is None:
-            state_one = None       # _metric_keys won't trace anything
-        elif init_inside:
-            state_one = jax.eval_shape(
-                lambda: afto_lib.init_state(problem, hyper))
-        else:
-            state_one = jax.tree.map(lambda x: x[0], states)
+        # None: the abstract init_state, traced only for a metrics_fn
+        state_one = None if metrics_fn is None or init_inside else \
+            jax.tree.map(lambda x: x[0], states)
         keys = _metric_keys(problem, hyper, metrics_fn, state_one)
 
         cache_key = (id(problem), id(metrics_fn), _hyper_key(hyper),

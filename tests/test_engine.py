@@ -211,6 +211,104 @@ def test_scan_cache_hit_does_not_retrace():
     assert engine_lib.BUILD_COUNTS["scan"] == builds
 
 
+def _fresh_data(kind, prob):
+    from repro.data import stream as stream_lib
+
+    if kind == "plain":
+        return None
+    if kind == "host":
+        return jax.tree.map(lambda x: x + 0.5, prob.data)
+
+    def sample(key):
+        ka, kb = jax.random.split(key)
+        return {"A": jax.random.normal(ka, (3, 3)) * 0.3,
+                "b": jax.random.normal(kb, (3,))}
+
+    return stream_lib.make_stream(sample, prob.n_workers, 7)
+
+
+def _assert_runs_equal(a, b, records=None):
+    for x, y in zip(jax.tree.leaves(a.state), jax.tree.leaves(b.state)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    for k in records or a.history:
+        np.testing.assert_array_equal(a.history[k], b.history[k])
+
+
+@pytest.mark.parametrize("kind", ["plain", "host", "stream"])
+def test_fresh_scan_equals_scan_from_init_state(kind):
+    """A fresh solve builds its initial state and history buffers inside
+    its program; it matches a solve handed `init_state` bit for bit."""
+    from repro.core import afto as afto_lib
+
+    prob = make_quadratic_problem()
+    hyper = _hyper()
+    schedule = StragglerScheduler(_cfg()).precompute(30)
+    data = _fresh_data(kind, prob)
+    fresh = run_scanned(prob, hyper, schedule, metrics_every=4, data=data)
+    given = run_scanned(prob, hyper, schedule, metrics_every=4, data=data,
+                        state=afto_lib.init_state(prob, hyper))
+    _assert_runs_equal(fresh, given,
+                       ("t", "gap_sq", "n_cuts_i", "n_cuts_ii", "sim_time",
+                        "max_staleness"))
+    assert fresh.history["gap_sq"].shape == (8,)
+
+
+@pytest.mark.parametrize("kind", ["plain", "stream"])
+def test_chunked_fresh_first_chunk_equals_unchunked(kind):
+    """`run_chunked` starts fresh (the state built in the program) and
+    continues from the carried state: its first chunk is the solve of
+    that slice from `init_state`, and the whole equals one fresh solve."""
+    from repro.core import afto as afto_lib
+
+    prob = make_quadratic_problem()
+    hyper = _hyper()
+    schedule = StragglerScheduler(_cfg()).precompute(30)
+    data = _fresh_data(kind, prob)
+    seen = []
+    chunked = engine_lib.run_chunked(
+        prob, hyper, schedule, 10, metrics_every=5, data=data,
+        chunk_hook=lambda st, t: seen.append(st))
+    first = run_scanned(prob, hyper, schedule.slice(0, 10), metrics_every=5,
+                        data=data, state=afto_lib.init_state(prob, hyper))
+    for x, y in zip(jax.tree.leaves(seen[0]), jax.tree.leaves(first.state)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    whole = run_scanned(prob, hyper, schedule, metrics_every=5, data=data)
+    _assert_runs_equal(chunked, whole,
+                       ("t", "gap_sq", "n_cuts_i", "n_cuts_ii", "sim_time"))
+
+
+def test_warm_fresh_scan_is_one_dispatch(monkeypatch):
+    """On a warm cache hit a fresh solve calls `init_state` nowhere on
+    the host: it ran once, inside the build, and `INIT_COUNTS` says the
+    program built the state."""
+    from repro.core import afto as afto_lib
+
+    calls = []
+    real = afto_lib.init_state
+    monkeypatch.setattr(afto_lib, "init_state",
+                        lambda *a: (calls.append(1), real(*a))[1])
+    prob = make_quadratic_problem()
+    hyper = _hyper()
+    schedule = StragglerScheduler(_cfg()).precompute(12)
+    run_scanned(prob, hyper, schedule, metrics_every=6)
+    assert len(calls) == 1
+    builds = engine_lib.BUILD_COUNTS["scan"]
+    inits = dict(engine_lib.INIT_COUNTS)
+    del calls[:]
+    for n in (1, 2):
+        res = run_scanned(prob, hyper, schedule, metrics_every=6)
+        assert calls == []
+        assert engine_lib.BUILD_COUNTS["scan"] == builds
+        assert engine_lib.INIT_COUNTS == {"program": inits["program"] + n,
+                                          "host": inits["host"]}
+    assert np.all(np.isfinite(res.history["gap_sq"]))
+    # a passed state is used as given: no count, and not traced again
+    run_scanned(prob, hyper, schedule, metrics_every=6,
+                state=real(prob, hyper))
+    assert engine_lib.INIT_COUNTS["program"] == inits["program"] + 2
+    assert engine_lib.INIT_COUNTS["host"] == inits["host"]
+
+
 # ---------------------------------------------------------------------------
 # batched sweep: swept rows must reproduce individual scanned runs
 # ---------------------------------------------------------------------------
